@@ -1,5 +1,6 @@
-"""Kernel microbenchmarks (interpret mode on CPU = correctness-path timing;
-real TPU timing is out of scope for this container — see §Roofline).
+"""Kernel microbenchmarks.  The platform decides how the kernels run:
+interpreted on CPU (a correctness-path timing that says nothing about the
+chip), compiled on TPU.
 
     PYTHONPATH=src python benchmarks/kernels_micro.py --smoke
     PYTHONPATH=src python benchmarks/kernels_micro.py --smoke --json out.json
@@ -11,9 +12,9 @@ Two kinds of rows:
 * **decode layer step, fused vs unfused** — the same jitted serving
   ``decode_step`` (reduced spiking arch, pallas backend) run through both
   :class:`repro.kernels.plan.DecodePlan` strategies.  The fused plan
-  launches ONE megakernel per decoder layer (bit-plane packing, Q/K/V,
-  SSA decode, attention-out and FFN tail all inside the kernel, spike
-  trains staying packed in VMEM); the unfused plan is the per-primitive
+  launches ONE megakernel per decoder layer (Q/K/V, SSA decode,
+  attention-out and FFN tail all inside the kernel, spike trains staying
+  in VMEM); the unfused plan is the per-primitive
   path with an HBM round-trip between every stage.  Their ratio
   ``fused_vs_unfused_step`` (unfused us / fused us, higher = fused wins)
   is machine-robust — both legs run in the same process on the same
@@ -58,19 +59,18 @@ def _primitive_rows():
     rows = []
     t, b, h, n, d = 2, 1, 2, 64, 32
     q = jax.random.bernoulli(key, 0.3, (t, b, h, n, d)).astype(jnp.uint8)
-    us = _median3(ops.ssa_attention_packed, q, q, q, key, causal=False,
-                  interpret=True)
+    us = _median3(ops.ssa_attention_packed, q, q, q, key, causal=False)
     rows.append(("kernels/ssa_attention_packed", us,
                  f"shape=T{t}B{b}H{h}N{n}D{d}"))
 
     cur = jax.random.normal(key, (8, 4096))
-    us = _median3(ops.lif_fused, cur, interpret=True)
+    us = _median3(ops.lif_fused, cur)
     rows.append(("kernels/lif_fused", us, "shape=8x4096"))
 
     sp = jax.random.bernoulli(key, 0.3, (4, 32, 256)).astype(jnp.float32)
     w = jax.random.randint(key, (256, 256), -15, 16, jnp.int8)
     sc = jnp.full((256,), 0.05, jnp.float32)
-    us = _median3(ops.aimc_spiking_linear, sp, w, sc, interpret=True)
+    us = _median3(ops.aimc_spiking_linear, sp, w, sc)
     rows.append(("kernels/aimc_spiking_linear", us, "shape=4x32x256->256"))
     return rows
 

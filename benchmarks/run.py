@@ -32,6 +32,9 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     fast = "--full" not in sys.argv
     only = [a for a in sys.argv[1:] if not a.startswith("--")]
     print("name,us_per_call,derived")

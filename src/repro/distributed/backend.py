@@ -354,7 +354,7 @@ class ShardedBackend(_DecodeShims):
         axis = self.model_axis
         acfg = (sim or E._IDEAL_SIM).cfg
         inner = self.inner
-        use_kernel = getattr(inner, "interpret", None) is not None  # pallas
+        use_kernel = isinstance(inner, E.PallasBackend)
         x_in = self._x_spec(spikes.ndim, spikes.shape[1], axis)
         x_out = self._x_spec(spikes.ndim, spikes.shape[1], None)
 

@@ -60,6 +60,7 @@ from repro.kernels import decode_fused as KFD
 from repro.kernels import ops as KOPS
 from repro.kernels import ref as KREF
 from repro.kernels.plan import AttnSpec, KVView
+from repro.kernels.platform import interpret_mode
 
 Array = jax.Array
 
@@ -429,9 +430,10 @@ class IntegerBackend(_DecodeShims):
 class PallasBackend(_DecodeShims):
     """The accelerated engine: popcount SSA + fused LIF/crossbar kernels.
 
-    ``interpret=True`` executes the kernel bodies through the Pallas
-    interpreter (exact semantics, runs on CPU); on TPU pass
-    ``interpret=False`` for the compiled kernels.  Packing to uint32 lanes
+    ``interpret`` is decided by the platform
+    (:func:`repro.kernels.platform.interpret_mode`): the kernel bodies run
+    through the Pallas interpreter on CPU (exact semantics) and compile to
+    Mosaic on TPU; any other platform raises.  Packing to uint32 lanes
     and padding to kernel block multiples happen inside the backend —
     callers hand over plain ``[T,B,H,N,d]`` / ``[T,...,d_in]`` spike
     trains."""
@@ -440,8 +442,8 @@ class PallasBackend(_DecodeShims):
     differentiable = False
     bit_exact = True
 
-    def __init__(self, interpret: bool = True):
-        self.interpret = interpret
+    def __init__(self):
+        self.interpret = interpret_mode()
 
     def ssa_attention(self, key, q, k, v, *, causal=False):
         return KOPS.ssa_attention_packed(

@@ -48,8 +48,8 @@ def _kernel(s_ref, w_ref, scale_ref, bias_ref, out_ref, acc_ref, *, t_steps: int
 
     @pl.when(ib == n_in_blocks - 1)
     def _fire():
-        scale = scale_ref[...].astype(jnp.float32)  # [bout]
-        bias = bias_ref[...].astype(jnp.float32)  # [bout], digital per-column
+        scale = scale_ref[...].astype(jnp.float32)  # [1, bout]
+        bias = bias_ref[...].astype(jnp.float32)  # [1, bout], digital per-column
         # mirror the oracle's rounding structure exactly (see
         # kernels/ref.py "Float-rounding discipline"): first COMMIT the
         # scaled pre-activations — the store to the VMEM scratch is a
@@ -61,14 +61,14 @@ def _kernel(s_ref, w_ref, scale_ref, bias_ref, out_ref, acc_ref, *, t_steps: int
         # membrane sits within one ulp of v_thresh (found by the
         # property-based differential suite).
         for t in range(t_steps):
-            acc_ref[t] = acc_ref[t] * scale[None, :] + bias[None, :]
+            acc_ref[t] = acc_ref[t] * scale + bias
 
         def step(t, v):
-            pre = pl.load(acc_ref, (pl.ds(t, 1), slice(None), slice(None)))[0]
+            pre = acc_ref[t]
             v = beta * v + pre
             spike = (v >= v_thresh).astype(jnp.float32)
-            pl.store(out_ref, (pl.ds(t, 1), slice(None), slice(None)),
-                     spike.astype(out_ref.dtype)[None])
+            # the TPU has no f32 -> u8 conversion: go through int32
+            out_ref[t] = spike.astype(jnp.int32).astype(out_ref.dtype)
             return v * (1.0 - spike)
 
         jax.lax.fori_loop(0, t_steps, step,
@@ -109,7 +109,7 @@ def aimc_matmul_counts_kernel(
     block_b: int = 128,
     block_in: int = 128,
     block_out: int = 128,
-    interpret: bool = False,
+    interpret: bool,
 ) -> Array:
     """[T, B, d_out] f32 integer-valued crossbar counts (pre-LIF)."""
     t, b, d_in = spikes.shape
@@ -145,7 +145,7 @@ def _requant_kernel(t_ref, lv_ref, eps_ref, nu_ref, out_ref, *, t0: float,
     (maximum, exp/log power, gain, round, clip) matches
     ``repro.aimc_device._requantize`` / ``kernels.ref.drift_requantize_ref``
     exactly — bit-exactness of the fold is part of the kernel contract."""
-    t = jnp.maximum(t_ref[0], t0)
+    t = jnp.maximum(t_ref[0], t0)  # scalar from SMEM
     df = jnp.exp(-nu_ref[...] * jnp.log(t / t0))
     g = (lv_ref[...] + eps_ref[...]) * df * float(img_gain)
     out_ref[...] = jnp.clip(jnp.round(g), -127, 127).astype(jnp.int8)
@@ -161,7 +161,7 @@ def drift_requantize_kernel(
     img_gain: int = 1,
     block_in: int = 128,
     block_out: int = 128,
-    interpret: bool = False,
+    interpret: bool,
 ) -> Array:
     d_in, d_out = levels.shape
     block_in = min(block_in, d_in)
@@ -172,7 +172,7 @@ def drift_requantize_kernel(
     return pl.pallas_call(
         kern,
         grid=(d_in // block_in, d_out // block_out),
-        in_specs=[pl.BlockSpec((1,), lambda i, j: (0,)), tile, tile, tile],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), tile, tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((d_in, d_out), jnp.int8),
         interpret=interpret,
@@ -182,15 +182,15 @@ def drift_requantize_kernel(
 def aimc_spiking_linear_kernel(
     spikes: Array,  # [T, B, d_in] binary (any float/int dtype)
     w_levels: Array,  # [d_in, d_out] int8 (5-bit conductance-pair levels)
-    scale: Array,  # [d_out] f32 per-column scale
-    bias: Array,  # [d_out] f32 digital bias added to each timestep's current
+    scale: Array,  # [1, d_out] f32 per-column scale
+    bias: Array,  # [1, d_out] f32 digital bias added to each timestep's current
     *,
     beta: float = 0.5,
     v_thresh: float = 1.0,
     block_b: int = 128,
     block_in: int = 128,
     block_out: int = 128,
-    interpret: bool = False,
+    interpret: bool,
 ) -> Array:
     t, b, d_in = spikes.shape
     d_out = w_levels.shape[1]
@@ -208,8 +208,8 @@ def aimc_spiking_linear_kernel(
         in_specs=[
             pl.BlockSpec((t, block_b, block_in), lambda ib, io, ii: (0, ib, ii)),
             pl.BlockSpec((block_in, block_out), lambda ib, io, ii: (ii, io)),
-            pl.BlockSpec((block_out,), lambda ib, io, ii: (io,)),
-            pl.BlockSpec((block_out,), lambda ib, io, ii: (io,)),
+            pl.BlockSpec((1, block_out), lambda ib, io, ii: (0, io)),
+            pl.BlockSpec((1, block_out), lambda ib, io, ii: (0, io)),
         ],
         out_specs=pl.BlockSpec((t, block_b, block_out), lambda ib, io, ii: (0, ib, io)),
         out_shape=jax.ShapeDtypeStruct((t, b, d_out), jnp.uint8),

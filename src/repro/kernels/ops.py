@@ -6,7 +6,8 @@ These are what the framework calls; each wrapper
 * draws the comparator integers from a counter-based PRNG (the software
   stand-in for the SSA engine's shared 32-bit LFSR array — all four bytes
   of each word are used, per §IV-B-3 / core.spikes.split_prn_bytes),
-* and exposes ``interpret=`` so CPU tests execute the kernel body exactly.
+* and resolve ``interpret=None`` through :func:`repro.kernels.platform.
+  interpret_mode` (interpreted on CPU, compiled on TPU).
 """
 
 from __future__ import annotations
@@ -21,11 +22,16 @@ from repro.kernels.aimc_matmul import (aimc_matmul_counts_kernel,
                                        aimc_spiking_linear_kernel,
                                        drift_requantize_kernel)
 from repro.kernels.lif import lif_kernel
+from repro.kernels.platform import interpret_mode
 from repro.kernels.ssa_attention import (ssa_attention_kernel,
                                          ssa_decode_kernel,
                                          ssa_decode_paged_kernel)
 
 Array = jax.Array
+
+
+def _rup(x: int, m: int) -> int:
+    return (x + m - 1) // m * m
 
 
 def pack_bits(x: Array, axis: int = -1) -> Array:
@@ -66,7 +72,7 @@ def ssa_attention_packed(
     key: Array,
     *,
     causal: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     """Bit-packed SSA attention; returns uint8 spikes [T,B,H,N,D].
 
@@ -98,7 +104,8 @@ def ssa_attention_packed(
     kp = pack_bits(kf, axis=-1)
     vp = pack_bits(vf, axis=-2)  # pack over n': [G, N/32, D]
     out = ssa_attention_kernel(
-        qp, kp, vp, rs, ra, n=np_, d=dp_, causal=causal, interpret=interpret
+        qp, kp, vp, rs, ra, n=np_, d=dp_, causal=causal,
+        interpret=interpret_mode(interpret),
     )
     return out[:, :n, :d].reshape(t, b, h, n, d)
 
@@ -151,7 +158,7 @@ def ssa_attention_decode_packed(
     h0: Union[int, Array] = 0,  # global index of q's first head (TP shards)
     *,
     i_max: int,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     """Bit-packed SSA decode step; returns uint8 spikes [T,B,H,1,D].
 
@@ -186,7 +193,8 @@ def ssa_attention_decode_packed(
     qp = pack_bits(qf, axis=-1)  # [G, 1, D/32]
     kp = pack_bits(kf, axis=-1)  # [G, L, D/32]
     vp = pack_bits(vf, axis=-2)  # [G, L/32, D]
-    out = ssa_decode_kernel(qp, kp, vp, rs, ra, interpret=interpret)
+    out = ssa_decode_kernel(qp, kp, vp, rs, ra,
+                            interpret=interpret_mode(interpret))
     out = out[:, :, :d].reshape(b, t, h, 1, d)
     return jnp.moveaxis(out, 0, 1)
 
@@ -215,7 +223,7 @@ def ssa_attention_decode_paged_packed(
     h0: Union[int, Array] = 0,  # global index of q's first head (TP shards)
     *,
     i_max: int,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     """Bit-packed *paged* SSA decode step; returns uint8 spikes [T,B,H,1,D].
 
@@ -253,31 +261,36 @@ def ssa_attention_decode_paged_packed(
         vf = jnp.pad(vf, ((0, 0),) * 3 + ((0, p_pad), (0, d_pad)))
         rs = jnp.pad(rs, ((0, 0),) * 5 + ((0, p_pad),))
         ra = jnp.pad(ra, ((0, 0),) * 4 + ((0, d_pad),))
-    rs = rs.reshape(b, t, h, 1, mp * plp)
+    # one [1, PLp] row per page, in its own leading slot: a TPU block must
+    # span the whole of the last two axes or align with the (8, 128) tile
+    rs = rs.reshape(b, t, h, mp, 1, plp)
     qp = pack_bits(qf, axis=-1)  # [B, T, H, 1, Wd]
     kp = pack_bits(kf, axis=-1)  # [P, T, KV, PLp, Wd]
     vp = pack_bits(vf, axis=-2)  # [P, T, KV, Wp, Dp]
     out = ssa_decode_paged_kernel(
-        page_table.astype(jnp.int32), qp, kp, vp, rs, ra, interpret=interpret)
+        page_table.astype(jnp.int32), qp, kp, vp, rs, ra,
+        interpret=interpret_mode(interpret))
     return jnp.moveaxis(out[..., :d], 0, 1)  # [T, B, H, 1, D]
 
 
 @partial(jax.jit, static_argnames=("beta", "v_thresh", "interpret"))
 def lif_fused(currents: Array, *, beta: float = 0.5, v_thresh: float = 1.0,
-              interpret: bool = True) -> Array:
-    """Fused LIF over [T, ...] currents; returns uint8 spikes."""
+              interpret: Optional[bool] = None) -> Array:
+    """Fused LIF over [T, ...] currents; returns uint8 spikes.
+
+    The feature axis is flattened, zero-padded and laid out as 128-lane
+    rows in blocks of up to 256 rows (a multiple of the 32-row uint8
+    tile)."""
     t = currents.shape[0]
     flat = currents.reshape(t, -1)
     m = flat.shape[1]
-    block = 4096
-    pad = (-m) % min(block, max(m, 1))
-    if m < block:
-        block = m + pad
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    out = lif_kernel(flat, beta=beta, v_thresh=v_thresh, block=block,
-                     interpret=interpret)
-    return out[:, :m].reshape(currents.shape)
+    rows = _rup(-(-m // 128), 32)
+    block_rows = min(256, rows)
+    rows = _rup(rows, block_rows)
+    flat = jnp.pad(flat, ((0, 0), (0, rows * 128 - m)))
+    out = lif_kernel(flat.reshape(t, rows, 128), beta=beta, v_thresh=v_thresh,
+                     block_rows=block_rows, interpret=interpret_mode(interpret))
+    return out.reshape(t, rows * 128)[:, :m].reshape(currents.shape)
 
 
 @partial(jax.jit, static_argnames=("beta", "v_thresh", "interpret"))
@@ -289,27 +302,25 @@ def aimc_spiking_linear(
     *,
     beta: float = 0.5,
     v_thresh: float = 1.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     t, b, d_in = spikes.shape
     d_out = w_levels.shape[1]
 
-    def rup(x, m):
-        return (x + m - 1) // m * m
-
-    bb = rup(b, 8) if b < 128 else rup(b, 128)
-    di = rup(d_in, 128)
-    do = rup(d_out, 128)
+    bb = _rup(b, 8) if b < 128 else _rup(b, 128)
+    di = _rup(d_in, 128)
+    do = _rup(d_out, 128)
     sp = jnp.pad(spikes, ((0, 0), (0, bb - b), (0, di - d_in)))
     wp = jnp.pad(w_levels, ((0, di - d_in), (0, do - d_out)))
-    sc = jnp.pad(scale, (0, do - d_out))
+    sc = jnp.pad(scale, (0, do - d_out)).reshape(1, do)
     if bias is None:
-        bi = jnp.zeros((do,), jnp.float32)
+        bi = jnp.zeros((1, do), jnp.float32)
     else:
-        bi = jnp.pad(bias.astype(jnp.float32), (0, do - d_out))
+        bi = jnp.pad(bias.astype(jnp.float32), (0, do - d_out)).reshape(1, do)
     out = aimc_spiking_linear_kernel(
         sp, wp, sc, bi, beta=beta, v_thresh=v_thresh,
-        block_b=min(bb, 128), block_in=128, block_out=128, interpret=interpret,
+        block_b=min(bb, 128), block_in=128, block_out=128,
+        interpret=interpret_mode(interpret),
     )
     return out[:, :b, :d_out]
 
@@ -319,7 +330,7 @@ def aimc_matmul_counts(
     spikes: Array,  # [T, B, d_in]
     w_levels: Array,  # [d_in, d_out] int8
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     """[T, B, d_out] f32 integer-valued crossbar counts (pre-scale/LIF).
 
@@ -331,17 +342,14 @@ def aimc_matmul_counts(
     t, b, d_in = spikes.shape
     d_out = w_levels.shape[1]
 
-    def rup(x, m):
-        return (x + m - 1) // m * m
-
-    bb = rup(b, 8) if b < 128 else rup(b, 128)
-    di = rup(d_in, 128)
-    do = rup(d_out, 128)
+    bb = _rup(b, 8) if b < 128 else _rup(b, 128)
+    di = _rup(d_in, 128)
+    do = _rup(d_out, 128)
     sp = jnp.pad(spikes, ((0, 0), (0, bb - b), (0, di - d_in)))
     wp = jnp.pad(w_levels, ((0, di - d_in), (0, do - d_out)))
     out = aimc_matmul_counts_kernel(
         sp, wp, block_b=min(bb, 128), block_in=128, block_out=128,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )
     return out[:, :b, :d_out]
 
@@ -355,7 +363,7 @@ def drift_requantize(
     *,
     t0: float,
     img_gain: int = 1,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     """Drifted-conductance requantisation on the Pallas path.
 
@@ -366,17 +374,14 @@ def drift_requantize(
     ``repro.aimc_device.drift_to``) for any shape."""
     d_in, d_out = levels.shape
 
-    def rup(x, m):
-        return (x + m - 1) // m * m
-
-    di, do = rup(d_in, 128), rup(d_out, 128)
+    di, do = _rup(d_in, 128), _rup(d_out, 128)
     pad = ((0, di - d_in), (0, do - d_out))
     out = drift_requantize_kernel(
         jnp.pad(levels.astype(jnp.float32), pad),
         jnp.pad(eps.astype(jnp.float32), pad),
         jnp.pad(nu.astype(jnp.float32), pad),
         jnp.reshape(t_seconds, (1,)).astype(jnp.float32),
-        t0=t0, img_gain=img_gain, interpret=interpret,
+        t0=t0, img_gain=img_gain, interpret=interpret_mode(interpret),
     )
     return out[:d_in, :d_out]
 
@@ -397,7 +402,7 @@ def aimc_spiking_linear_programmed(
     img_gain: int = 1,
     beta: float = 0.5,
     v_thresh: float = 1.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     """End-to-end programmed-state spiking linear on the Pallas path.
 

@@ -35,17 +35,33 @@ Array = jax.Array
 
 
 def _popcount(x: Array) -> Array:
-    return lax.population_count(x)
+    # int32 counts: the TPU lowering has no reductions over unsigned ints
+    return lax.population_count(x).astype(jnp.int32)
 
 
 def _pack_bits_kernel_axis(s: Array) -> Array:
-    """Pack binary int32 [.., n, ..] -> uint32 along a *leading-of-last-two*
-    axis inside the kernel: s [N, N] -> [N, N//32] (pack axis = -1)."""
+    """Pack binary int32 [M, n] -> uint32 [M, n//32] inside the kernel.
+
+    The word ``w`` of row ``i`` is ``sum_j s[i, 32w + j] << j``.  A
+    reshape that splits the lane axis into (words, 32) is refused by the
+    TPU compiler, so the sum is two MXU products with constant
+    power-of-two matrices, one per 16-bit half: every operand (0/1 spikes,
+    powers of two up to 2^15) is exact in bf16 and every sum (< 2^16) is
+    exact in the f32 accumulator, so the packing is exact on any backend.
+    """
     n = s.shape[-1]
     w = n // 32
-    s3 = s.reshape(*s.shape[:-1], w, 32).astype(jnp.uint32)
-    weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
-    return jnp.sum(s3 * weights, axis=-1, dtype=jnp.uint32)
+    j = lax.broadcasted_iota(jnp.int32, (n, w), 0)
+    in_word = (j >> 5) == lax.broadcasted_iota(jnp.int32, (n, w), 1)
+    bit = j & 31
+    lo_w = jnp.where(in_word & (bit < 16), 1 << bit, 0).astype(jnp.float32)
+    hi_w = jnp.where(in_word & (bit >= 16), 1 << (bit - 16), 0
+                     ).astype(jnp.float32)
+    sf = s.astype(jnp.float32)
+    lo = jnp.dot(sf, lo_w, preferred_element_type=jnp.float32)
+    hi = jnp.dot(sf, hi_w, preferred_element_type=jnp.float32)
+    packed = (hi.astype(jnp.int32) << 16) | lo.astype(jnp.int32)
+    return lax.bitcast_convert_type(packed, jnp.uint32)
 
 
 def _ssa_kernel(qp_ref, kp_ref, vp_ref, rs_ref, ra_ref, out_ref, *, n: int, d: int, causal: bool):
@@ -112,7 +128,7 @@ def ssa_decode_kernel(
     rs: Array,  # [G, 1, L] i32
     ra: Array,  # [G, 1, D] i32
     *,
-    interpret: bool = False,
+    interpret: bool,
 ) -> Array:
     g, l, wd = kp.shape
     wl = vp.shape[1]
@@ -164,7 +180,7 @@ def _ssa_decode_paged_body(tbl_ref, qp_ref, kp_ref, vp_ref, rs_ref, ra_ref,
     qp = qp_ref[0, 0, 0]  # [1, Wd]
     kp = kp_ref[0, 0, 0]  # [PLp, Wd]
     counts_s = jnp.sum(_popcount(qp & kp), axis=-1).astype(jnp.int32)[None, :]
-    s = (counts_s > rs_ref[0, 0, 0]).astype(jnp.int32)  # [1, PLp]
+    s = (counts_s > rs_ref[0, 0, 0, 0]).astype(jnp.int32)  # [1, PLp]
     sp = _pack_bits_kernel_axis(s)  # [1, Wp]
     anded = jnp.swapaxes(sp, 0, 1) & vp_ref[0, 0, 0]  # [Wp, D]
     acc_ref[...] += jnp.sum(_popcount(anded), axis=0).astype(jnp.int32)[None, :]
@@ -179,10 +195,10 @@ def ssa_decode_paged_kernel(
     qp: Array,  # [B, T, H, 1, Wd] u32
     kp: Array,  # [P, T, KV, PLp, Wd] u32 — the physical key page pool
     vp: Array,  # [P, T, KV, Wp, D] u32 — value pool, packed along position
-    rs: Array,  # [B, T, H, 1, MP*PLp] i32
+    rs: Array,  # [B, T, H, MP, 1, PLp] i32
     ra: Array,  # [B, T, H, 1, D] i32
     *,
-    interpret: bool = False,
+    interpret: bool,
 ) -> Array:
     """Paged SSA decode: grid (slot, timestep, head, page-table column).
 
@@ -204,7 +220,8 @@ def ssa_decode_paged_kernel(
                          lambda ib, it, ih, j, tbl: (tbl[ib, j], it, ih // rep, 0, 0)),
             pl.BlockSpec((1, 1, 1, wp, d),
                          lambda ib, it, ih, j, tbl: (tbl[ib, j], it, ih // rep, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, plp), lambda ib, it, ih, j, tbl: (ib, it, ih, 0, j)),
+            pl.BlockSpec((1, 1, 1, 1, 1, plp),
+                         lambda ib, it, ih, j, tbl: (ib, it, ih, j, 0, 0)),
             pl.BlockSpec((1, 1, 1, 1, d), lambda ib, it, ih, j, tbl: (ib, it, ih, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, 1, d),
@@ -229,7 +246,7 @@ def ssa_attention_kernel(
     n: int,
     d: int,
     causal: bool,
-    interpret: bool = False,
+    interpret: bool,
 ) -> Array:
     g, _, wd = qp.shape
     wn = vp.shape[1]

@@ -27,7 +27,9 @@ from pathlib import Path
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
-# TPU v5e-class hardware constants (per chip)
+# Modelled target: one TPU v5e chip (Google Cloud "TPU v5e" published
+# peaks).  The roofline terms below assume this chip whatever device the
+# dry run compiles on; they are estimates, not measurements.
 PEAK_FLOPS = 197e12  # bf16
 HBM_BW = 819e9  # B/s
 ICI_BW = 50e9  # B/s per link
@@ -114,10 +116,7 @@ def count_active_params(cfg, abstract_params) -> int:
     import jax
 
     total = 0
-    # jax.tree.flatten_with_path is jax >= 0.5; fall back to tree_util
-    flatten_with_path = getattr(jax.tree, "flatten_with_path",
-                                jax.tree_util.tree_flatten_with_path)
-    flat = flatten_with_path(abstract_params)[0]
+    flat = jax.tree.flatten_with_path(abstract_params)[0]
     for path, leaf in flat:
         keys = [getattr(p, "key", getattr(p, "name", "")) for p in path]
         frac = 1.0

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import aimc_device as AD
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import ParallelConfig
 from repro.configs.registry import get_config, reduced_config
 from repro.engine import get_backend
@@ -95,7 +96,8 @@ def serve(
 ):
     """Serve ``n_requests`` synthetic prompts; returns their outputs in
     submission order (continuous batching: a finished slot is refilled from
-    the queue without draining the batch)."""
+    the queue without draining the batch) and the scheduler that served
+    them (its stats, device state and compiled step)."""
     cfg = reduced_config(arch) if smoke else get_config(arch)
     if cfg.frontend != "none":
         print(f"[serve] {arch} is a {cfg.family} backbone; serving over stub embeddings")
@@ -159,7 +161,7 @@ def serve(
     sch.attach_obs(obs)
     if http:
         _serve_http(sch, host=host, port=port)
-        return []
+        return [], sch
     rng = jax.random.PRNGKey(seed + 1)
     prompts: List[jnp.ndarray] = [
         jax.random.randint(jax.random.fold_in(rng, i), (int(4 + 3 * (i % 4)),), 0,
@@ -189,7 +191,7 @@ def serve(
     if program:
         print(f"[serve] device clock t={st.t_device_s:.1f}s, "
               f"{st.recalibrations} GDC recalibrations")
-    return [outs[r] for r in rids]
+    return [outs[r] for r in rids], sch
 
 
 def _serve_http(sch: BatchScheduler, *, host: str, port: int) -> None:
@@ -277,6 +279,7 @@ def main(argv=None):
     ap.add_argument("--recal-every", type=float, default=0.0,
                     help="GDC recalibration interval in device seconds (0 = never)")
     a = ap.parse_args(argv)
+    use_compile_cache()
     serve(a.arch, smoke=a.smoke, n_requests=a.requests, slots=a.slots,
           max_new=a.max_new, cache_len=a.cache_len, backend=a.backend,
           program=a.program, drift_step_s=a.drift_step,

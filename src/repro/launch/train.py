@@ -28,6 +28,7 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import ParallelConfig
 from repro.configs.registry import get_config, reduced_config
 from repro.checkpoint.manager import CheckpointManager
@@ -149,6 +150,7 @@ def main(argv=None):
     ap.add_argument("--mesh", type=int, nargs="+", default=[1, 1])
     ap.add_argument("--seed", type=int, default=0)
     a = ap.parse_args(argv)
+    use_compile_cache()
     run(
         a.arch, smoke=a.smoke, steps=a.steps, batch=a.batch, seq_len=a.seq_len,
         ckpt_dir=a.ckpt_dir, ckpt_every=a.ckpt_every, fail_at=a.fail_at,

@@ -47,15 +47,7 @@ from repro.models.layers import ParamDef, _act
 
 Array = jax.Array
 
-# jax.shard_map landed in jax 0.6; on the pinned 0.4.x it lives under
-# jax.experimental (with replication checking named check_rep, off by
-# default behaviourally equivalent to check_vma=False here).
-if hasattr(jax, "shard_map"):
-    _shard_map = partial(jax.shard_map, check_vma=False)
-else:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _eshard_map
-
-    _shard_map = partial(_eshard_map, check_rep=False)
+_shard_map = partial(jax.shard_map, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -10,16 +10,17 @@ it ``N`` steps later.  The capture is TensorBoard/Perfetto-compatible
 ui.perfetto.dev).
 
 The profiler is pure host-side control flow around the already-compiled
-step — arming it cannot recompile or perturb token streams.  Failures to
-start a capture (missing profiler backend in a stripped container) are
-reported once and disable the hook rather than killing the serve loop:
-profiling is observability, not a correctness dependency.
+step — arming it cannot recompile or perturb token streams.  A capture
+that was asked for and cannot start or stop raises: a run that was meant
+to be traced and silently was not would be read as a traced one.
 """
 
 from __future__ import annotations
 
 import sys
 from typing import Optional
+
+import jax
 
 
 class StepProfiler:
@@ -32,7 +33,7 @@ class StepProfiler:
         self.out_dir = out_dir
         self.skip = skip
         self._seen = 0
-        self._state = "armed"  # armed -> tracing -> done | failed
+        self._state = "armed"  # armed -> tracing -> done
 
     @property
     def tracing(self) -> bool:
@@ -40,23 +41,16 @@ class StepProfiler:
 
     @property
     def done(self) -> bool:
-        return self._state in ("done", "failed")
+        return self._state == "done"
 
     def tick(self) -> None:
         """Call once per completed decode step (after device sync)."""
         if self.done:
             return
         if self._state == "armed" and self._seen == self.skip:
-            try:
-                import jax
-
-                jax.profiler.start_trace(self.out_dir)
-                self._state = "tracing"
-                self._t0_step = self._seen
-            except Exception as e:  # pragma: no cover - backend-dependent
-                self._state = "failed"
-                print(f"[obs] jax.profiler capture unavailable: {e}",
-                      file=sys.stderr)
+            jax.profiler.start_trace(self.out_dir)
+            self._state = "tracing"
+            self._t0_step = self._seen
         self._seen += 1
         if self._state == "tracing" and self._seen - self._t0_step >= self.steps:
             self.stop()
@@ -65,12 +59,7 @@ class StepProfiler:
         """Stop an in-flight capture (also called on scheduler drain)."""
         if self._state != "tracing":
             return None
-        try:
-            import jax
-
-            jax.profiler.stop_trace()
-        except Exception as e:  # pragma: no cover - backend-dependent
-            print(f"[obs] jax.profiler stop failed: {e}", file=sys.stderr)
+        jax.profiler.stop_trace()
         self._state = "done"
         print(f"[obs] captured {self.steps} decode steps to {self.out_dir} "
               "(tensorboard --logdir, or open the .trace.json.gz in "

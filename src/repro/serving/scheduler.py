@@ -830,7 +830,7 @@ class BatchScheduler:
         if not any(r is not None for r in self._slot_req):
             return 0
         t0 = time.perf_counter()  # monotonic: durations must survive NTP
-        logits, self.state, act = self._decode(self.params, self.state)
+        logits, self.state, act = self._decode(*self._decode_args())
         nxt = np.asarray(self.state.tokens)  # syncs the step
         step_s = time.perf_counter() - t0
         self.stats.decode_s += step_s
@@ -877,10 +877,8 @@ class BatchScheduler:
         if not any(r is not None for r in self._slot_req):
             return 0
         b = self.slots
-        feed_tok = np.zeros((b,), np.int32)
-        feed_seed = np.zeros((b,), np.uint32)
-        feed_mask = np.zeros((b,), bool)
-        write_pids = np.full((b,), ST.TRASH_PAGE, np.int32)
+        feed = self._blank_feed()
+        feed_tok, feed_seed, feed_mask, write_pids = feed
         for slot in range(b):
             req = self._slot_req[slot]
             if req is None:
@@ -909,10 +907,7 @@ class BatchScheduler:
                 feed_seed[slot] = req.seed
                 feed_mask[slot] = True
         t0 = time.perf_counter()  # monotonic: durations must survive NTP
-        logits, self.state, act = self._decode(
-            self.params, self.state, jnp.asarray(feed_tok),
-            jnp.asarray(feed_seed), jnp.asarray(feed_mask),
-            jnp.asarray(write_pids))
+        logits, self.state, act = self._decode(*self._decode_args(feed))
         nxt = np.asarray(self.state.tokens)  # syncs the step
         step_s = time.perf_counter() - t0
         self.stats.decode_s += step_s
@@ -1009,6 +1004,28 @@ class BatchScheduler:
         self.stats.t_device_s = self._t_device
         if self.obs is not None:
             self._g_clock.set(self._t_device)
+
+    def _blank_feed(self):
+        """The paged step's per-slot feed (token, PRN seed, feed mask,
+        write page), zeroed with every slot writing the trash page;
+        :meth:`_step_paged` fills in the occupied slots."""
+        b = self.slots
+        return (np.zeros((b,), np.int32), np.zeros((b,), np.uint32),
+                np.zeros((b,), bool), np.full((b,), ST.TRASH_PAGE, np.int32))
+
+    def _decode_args(self, feed=None):
+        """Arguments of the jitted step: params, state and, when paged,
+        the per-slot feed (a blank one if none is given)."""
+        if not self.paged:
+            return self.params, self.state
+        feed = self._blank_feed() if feed is None else feed
+        return (self.params, self.state) + tuple(jnp.asarray(x) for x in feed)
+
+    def lower_decode_step(self):
+        """The jitted batched decode step, lowered at the current state's
+        shapes: ``.as_text()`` shows the program each step runs (e.g. its
+        Mosaic kernels), without executing it."""
+        return self._decode.lower(*self._decode_args())
 
     def run(self) -> Dict[int, List[int]]:
         """Serve until the queue and all slots drain; returns outputs."""

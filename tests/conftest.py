@@ -1,6 +1,4 @@
 import os
-import sys
-import types
 
 # Force an 8-device host platform (before the jax import below initialises
 # the backend) so the distributed-execution tests (tests/test_distributed.py)
@@ -16,99 +14,6 @@ import jax
 import pytest
 
 jax.config.update("jax_enable_x64", False)
-
-# ---------------------------------------------------------------------------
-# Optional-dependency gate: hypothesis.
-#
-# The property tests use a small, fixed subset of the hypothesis API
-# (@given with keyword strategies: integers / sampled_from / booleans /
-# just, plus @settings).  When the real package is available (CI installs
-# it from pyproject.toml) it is used unchanged; on bare containers without
-# it we install a deterministic fallback that degrades each @given test to
-# a fixed, well-spread sample of the strategy product space — the suite
-# still collects and genuinely exercises the properties, just on fixed
-# seeds instead of shrinking random search.
-# ---------------------------------------------------------------------------
-
-try:  # pragma: no cover - environment-dependent
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:  # pragma: no cover - environment-dependent
-
-    class _Strategy:
-        def __init__(self, examples):
-            self.examples = list(examples)
-
-    def _integers(lo, hi):
-        span = hi - lo
-        vals = {lo, hi, lo + span // 2, lo + span // 3, lo + (2 * span) // 3,
-                lo + 1 if span >= 1 else lo, hi - 1 if span >= 1 else hi}
-        return _Strategy(sorted(v for v in vals if lo <= v <= hi))
-
-    def _sampled_from(seq):
-        return _Strategy(seq)
-
-    def _booleans():
-        return _Strategy([False, True])
-
-    def _just(value):
-        return _Strategy([value])
-
-    _MAX_FALLBACK_EXAMPLES = 8
-
-    def _given(**strategies):
-        names = list(strategies)
-
-        def deco(fn):
-            def wrapper(*args, **kwargs):
-                pools = [strategies[n].examples for n in names]
-                total = 1
-                for p in pools:
-                    total *= max(len(p), 1)
-                n_runs = min(total, _MAX_FALLBACK_EXAMPLES)
-                # deterministic, well-spread walk of the product space:
-                # golden-ratio (Fibonacci) index hashing decorrelates the
-                # mixed-radix digits, unlike aligned round-robin cycles
-                seen = set()
-                for i in range(total):
-                    idx = (i * 2654435761) % total
-                    if idx in seen:
-                        continue
-                    seen.add(idx)
-                    drawn = {}
-                    for n, p in zip(names, pools):
-                        idx, r = divmod(idx, max(len(p), 1))
-                        drawn[n] = p[r]
-                    fn(*args, **kwargs, **drawn)
-                    if len(seen) >= n_runs:
-                        break
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.hypothesis_fallback = True
-            return wrapper
-
-        return deco
-
-    def _settings(**_kwargs):
-        def deco(fn):
-            return fn
-
-        return deco
-
-    _stub = types.ModuleType("hypothesis")
-    _stub.given = _given
-    _stub.settings = _settings
-    _stub.HealthCheck = types.SimpleNamespace(
-        too_slow="too_slow", data_too_large="data_too_large",
-        filter_too_much="filter_too_much")
-    _strategies = types.ModuleType("hypothesis.strategies")
-    _strategies.integers = _integers
-    _strategies.sampled_from = _sampled_from
-    _strategies.booleans = _booleans
-    _strategies.just = _just
-    _stub.strategies = _strategies
-    sys.modules["hypothesis"] = _stub
-    sys.modules["hypothesis.strategies"] = _strategies
 
 
 @pytest.fixture(scope="session")

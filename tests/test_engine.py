@@ -2,7 +2,7 @@
 
 The contract of ``repro.engine``:
 
-* ``pallas`` (interpret=True) is **bit-exact** against the ``integer``
+* ``pallas`` (interpreted on CPU) is **bit-exact** against the ``integer``
   hardware oracle given the same PRNG key — through the *full* spiking
   ViT and GPT forwards, not just per-kernel.
 * ``reference`` (float + straight-through) agrees with ``integer`` in
@@ -144,8 +144,8 @@ def test_backend_registry():
     assert isinstance(get_backend("reference"), ReferenceBackend)
     assert isinstance(get_backend("integer"), IntegerBackend)
     assert isinstance(get_backend(None), ReferenceBackend)
-    pb = get_backend("pallas", interpret=True)
-    assert isinstance(pb, PallasBackend) and pb.interpret
+    pb = get_backend("pallas")
+    assert isinstance(pb, PallasBackend) and pb.interpret  # CPU: interpreted
     inst = IntegerBackend()
     assert get_backend(inst) is inst
     with pytest.raises(KeyError):

@@ -1,4 +1,4 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle.
+"""Per-kernel shape/dtype sweeps: Pallas (interpreted on CPU) vs pure-jnp oracle.
 
 All three kernels are integer/bit-exact, so the assertion is equality."""
 
@@ -24,7 +24,7 @@ def test_ssa_kernel_matches_ref(t, b, h, n, d, causal):
     q = jax.random.bernoulli(ks[0], 0.3, (t, b, h, n, d)).astype(jnp.uint8)
     k = jax.random.bernoulli(ks[1], 0.5, (t, b, h, n, d)).astype(jnp.uint8)
     v = jax.random.bernoulli(ks[2], 0.6, (t, b, h, n, d)).astype(jnp.uint8)
-    out = ops.ssa_attention_packed(q, k, v, ks[3], causal=causal, interpret=True)
+    out = ops.ssa_attention_packed(q, k, v, ks[3], causal=causal)
     g = t * b * h
     rs, ra = ops.draw_comparator_prns(ks[3], (g, n, n), (g, n, d), d, n)
     exp = ref.ssa_attention_ref(
@@ -37,7 +37,7 @@ def test_ssa_kernel_matches_ref(t, b, h, n, d, causal):
 def test_ssa_kernel_input_dtypes(dtype):
     key = jax.random.PRNGKey(0)
     q = jax.random.bernoulli(key, 0.5, (1, 1, 1, 32, 32)).astype(dtype)
-    out = ops.ssa_attention_packed(q, q, q, key, interpret=True)
+    out = ops.ssa_attention_packed(q, q, q, key)
     assert out.dtype == jnp.uint8
 
 
@@ -53,7 +53,7 @@ def test_pack_unpack_roundtrip():
 def test_lif_kernel_matches_ref(t, m, dtype):
     key = jax.random.PRNGKey(t * m)
     cur = (jax.random.normal(key, (t, m)) * 1.3).astype(dtype)
-    out = ops.lif_fused(cur, interpret=True)
+    out = ops.lif_fused(cur)
     exp = ref.lif_ref(cur)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))
 
@@ -61,7 +61,7 @@ def test_lif_kernel_matches_ref(t, m, dtype):
 @pytest.mark.parametrize("beta,th", [(0.5, 1.0), (0.9, 0.5)])
 def test_lif_kernel_params(beta, th):
     cur = jnp.full((6, 256), 0.4, jnp.float32)
-    out = ops.lif_fused(cur, beta=beta, v_thresh=th, interpret=True)
+    out = ops.lif_fused(cur, beta=beta, v_thresh=th)
     exp = ref.lif_ref(cur, beta=beta, v_thresh=th)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))
 
@@ -78,7 +78,7 @@ def test_aimc_kernel_matches_ref(t, b, din, dout):
     sp = jax.random.bernoulli(ks[0], 0.35, (t, b, din)).astype(jnp.float32)
     w = jax.random.randint(ks[1], (din, dout), -15, 16, jnp.int8)
     sc = jax.random.uniform(ks[2], (dout,), jnp.float32, 0.01, 0.1)
-    out = ops.aimc_spiking_linear(sp, w, sc, interpret=True)
+    out = ops.aimc_spiking_linear(sp, w, sc)
     exp = ref.aimc_spiking_linear_ref(sp, w, sc)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))
 
@@ -91,6 +91,6 @@ def test_aimc_kernel_property(t, b, din, dout):
     sp = jax.random.bernoulli(key, 0.4, (t, b, din)).astype(jnp.float32)
     w = jax.random.randint(key, (din, dout), -15, 16, jnp.int8)
     sc = jnp.full((dout,), 0.05, jnp.float32)
-    out = ops.aimc_spiking_linear(sp, w, sc, interpret=True)
+    out = ops.aimc_spiking_linear(sp, w, sc)
     exp = ref.aimc_spiking_linear_ref(sp, w, sc)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))

@@ -265,6 +265,14 @@ def test_step_profiler_window(monkeypatch):
     with pytest.raises(ValueError):
         StepProfiler(0, "/tmp/prof")
 
+    def refuse(d):
+        raise RuntimeError("no profiler backend")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    q = StepProfiler(1, "/tmp/prof", skip=0)
+    with pytest.raises(RuntimeError):  # a requested capture fails the run
+        q.tick()
+
 
 # -- scheduler integration (backend matrix) -------------------------------
 

@@ -18,10 +18,7 @@ substrate-interchangeability contract op by op:
   LFSR integers, so it is distribution-equal but not bit-equal — those
   assertions stop at the digital pair by design (see ``repro.engine``).
 
-Under real hypothesis (CI) each property explores randomised examples;
-without it, the conftest fallback shim degrades to a deterministic,
-well-spread sample of each strategy product — fixed seeds, same
-assertions.
+Each property explores randomised hypothesis examples.
 """
 
 import jax

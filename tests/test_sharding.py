@@ -12,8 +12,8 @@ from repro.models import transformer as T
 from repro.optim import adamw as A
 from repro.parallel import sharding as SH
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_spec_divisibility_fallback():

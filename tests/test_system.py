@@ -35,7 +35,7 @@ def test_launcher_moe_arch(tmp_path):
 
 
 def test_serve_continuous_batching():
-    outs = LS.serve("yi-9b", n_requests=5, slots=2, max_new=4, cache_len=32)
+    outs, _ = LS.serve("yi-9b", n_requests=5, slots=2, max_new=4, cache_len=32)
     assert len(outs) == 5
     assert all(len(o) == 4 for o in outs)
 
