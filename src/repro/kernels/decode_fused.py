@@ -499,7 +499,7 @@ def fused_decode_layer_paged(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, mp),
+        grid=(b, mp),  # counted by DecodePlan.page_columns
         in_specs=[
             per_slot((t, d)),
             pl.BlockSpec((1, t, pl_, kv * hd), page),

@@ -106,6 +106,18 @@ class DecodePlan:
     def fused(self) -> bool:
         return self.kernel == "fused"
 
+    def page_columns(self, slots: int, max_pages: int) -> int:
+        """(slot, page-table column) pairs one layer's paged attention
+        kernel visits per decode step, live or not.  The fused
+        megakernel's grid is ``(slots, max_pages)``
+        (:func:`repro.kernels.decode_fused.fused_decode_layer_paged`); the
+        unfused kernel's is ``(slots, T, heads, max_pages)``, the same
+        columns once per timestep and head.  A grid that skips dead
+        columns changes this count with it."""
+        if self.layout != "paged":
+            raise ValueError(f"page columns of a {self.layout} plan")
+        return slots * max_pages
+
     def describe(self) -> str:
         geo = f", page_len={self.page_len}" if self.layout == "paged" else ""
         why = f" ({'; '.join(self.reasons)})" if self.reasons else ""

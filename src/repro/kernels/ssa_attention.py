@@ -213,7 +213,7 @@ def ssa_decode_paged_kernel(
     rep = h // kp.shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, t, h, mp),
+        grid=(b, t, h, mp),  # counted by DecodePlan.page_columns
         in_specs=[
             pl.BlockSpec((1, 1, 1, 1, wd), lambda ib, it, ih, j, tbl: (ib, it, ih, 0, 0)),
             pl.BlockSpec((1, 1, 1, plp, wd),

@@ -16,7 +16,9 @@ so a single object answers "what is this server doing right now":
   events, dumped to a postmortem file when a scheduler/page-pool
   invariant guard raises;
 * :attr:`Telemetry.profiler` — opt-in ``jax.profiler`` capture of N
-  decode steps (``--profile-steps`` / ``--profile-dir``).
+  decode steps (``--profile-steps`` / ``--profile-dir``);
+* :func:`span` — ``xpk.*`` host spans of the front door, the scheduler
+  and garbage collection, on the profiler's clock (:mod:`repro.obs.spans`).
 
 Everything here is host-side bookkeeping over the scheduler's existing
 Python loop: attaching telemetry never touches jitted code, never changes
@@ -41,7 +43,9 @@ from repro.obs.metrics import (
 )
 from repro.obs.profiler import StepProfiler
 from repro.obs.recorder import FlightRecorder
+from repro.obs.spans import install_gc_hook, span
 from repro.obs.trace import (
+    GUARD,
     JsonlSink,
     ListSink,
     Tracer,
@@ -66,10 +70,14 @@ class Telemetry:
                profiler: Optional[StepProfiler] = None) -> "Telemetry":
         """Standard bundle: registry + tracer + armed flight recorder (the
         recorder listens to the tracer, so guard-site dumps always have
-        recent history even when no external trace sink is attached)."""
+        recent history even when no external trace sink is attached), and
+        the process's garbage collections spanned and counted into the
+        registry."""
         recorder = FlightRecorder(ring_size=flight_ring, out_dir=flight_dir)
         tracer = Tracer([recorder])
-        return cls(metrics=MetricsRegistry(), tracer=tracer,
+        metrics = MetricsRegistry()
+        install_gc_hook(metrics)
+        return cls(metrics=metrics, tracer=tracer,
                    recorder=recorder, profiler=profiler)
 
     def trace(self, event: str, **fields) -> None:
@@ -80,7 +88,7 @@ class Telemetry:
         without a recorder); returns the dump path."""
         if self.recorder is None:
             return None
-        self.trace("guard_violation", reason=reason, **extra)
+        self.trace(GUARD, reason=reason, **extra)
         return self.recorder.dump(reason, registry=self.metrics,
                                   extra=extra or None)
 
@@ -101,5 +109,6 @@ __all__ = [
     "log_buckets",
     "perfetto_export",
     "render_prometheus",
+    "span",
     "write_perfetto",
 ]

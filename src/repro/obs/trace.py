@@ -37,7 +37,6 @@ SUBMIT = "submit"
 ADMIT = "admit"
 READMIT = "readmit"
 PREFILL_CHUNK = "prefill_chunk"
-HANDOFF = "handoff"
 FIRST_TOKEN = "first_token"
 DECODE = "decode"
 PREEMPT = "preempt"
@@ -46,8 +45,8 @@ FINISH = "finish"
 GDC_RECAL = "gdc_recal"
 GUARD = "guard_violation"
 
-LIFECYCLE = (SUBMIT, ADMIT, READMIT, PREFILL_CHUNK, HANDOFF, FIRST_TOKEN,
-             DECODE, PREEMPT, EVICT, FINISH, GDC_RECAL, GUARD)
+LIFECYCLE = (SUBMIT, ADMIT, READMIT, PREFILL_CHUNK, FIRST_TOKEN, DECODE,
+             PREEMPT, EVICT, FINISH, GDC_RECAL, GUARD)
 
 
 class Tracer:

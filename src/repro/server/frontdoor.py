@@ -42,7 +42,7 @@ from typing import Any, Deque, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from repro.obs import Telemetry
+from repro.obs import Telemetry, span
 from repro.obs import trace as TR
 from repro.server import admission as ADM
 from repro.server.admission import AdmissionController, TenantPolicy
@@ -349,19 +349,39 @@ class FrontDoor:
 
     def _pump_once(self) -> bool:
         """One admission + decode + streaming round.  Returns True when any
-        work happened (intake, admission, a decode step, streamed tokens)."""
+        work happened (intake, admission, a decode step, streamed tokens).
+        Its phases are the ``xpk.pump.*`` spans (:mod:`repro.obs.spans`)."""
+        with span("pump"):
+            with span("pump.intake"):
+                busy = self._take_in()
+            # strict priority + token fairness, energy throttle, slot/page
+            # backpressure (decisions recorded on transitions)
+            with span("pump.admit"):
+                busy |= self._admit()
+            # one batched decode step
+            stepped = bool(self._running)
+            if stepped:
+                self.sch.step()
+            with span("pump.stream"):
+                if stepped:
+                    self._stream_new_tokens()
+                self._set_gauges()
+        return busy or stepped
+
+    def _take_in(self) -> bool:
+        """Refill the energy buckets, drain the loop->pump intake into
+        per-tenant FIFO queues, and preempt running requests of
+        over-budget tenants (they park at the head of their tenant queue
+        and re-admit, bit-exact, once the bucket refills).  Returns True
+        when a request moved."""
         busy = False
         now = _now()
         self.adm.refill(now - self._last_refill)
         self._last_refill = now
-        # 1. drain the loop->pump intake into per-tenant FIFO queues
         while self._intake:
             req = self._intake.popleft()
             self._tenant_queue(req.tenant).append(req)
             busy = True
-        # 2. energy preemption: evict running requests of over-budget
-        #    tenants; they park at the head of their tenant queue and
-        #    re-admit (bit-exact resume) once the bucket refills
         for rid in [r for r in self._running]:
             req = self._running[rid]
             # liveness guard: a running request may only be preempted after
@@ -392,21 +412,16 @@ class FrontDoor:
                                    tenant=req.tenant, streamed=req.streamed)
                 self._tenant_queue(req.tenant).appendleft(req)
                 busy = True
-        # 3. admission: strict priority + token fairness, energy throttle,
-        #    slot/page backpressure (decisions recorded on transitions)
-        busy |= self._admit()
-        # 4. one batched decode step
-        if self._running:
-            self.sch.step()
-            busy = True
-            self._stream_new_tokens()
-        if self.obs is not None:
-            self._g_pending.set(float(self._pending_count))
-            self._g_running.set(float(len(self._running)))
-            for name, st in self.adm.tenants.items():
-                if st.policy.energy_budget_j is not None:
-                    self._g_credit.set(st.credit_j, name)
         return busy
+
+    def _set_gauges(self) -> None:
+        if self.obs is None:
+            return
+        self._g_pending.set(float(self._pending_count))
+        self._g_running.set(float(len(self._running)))
+        for name, st in self.adm.tenants.items():
+            if st.policy.energy_budget_j is not None:
+                self._g_credit.set(st.credit_j, name)
 
     def _admit(self) -> bool:
         sch, adm = self.sch, self.adm

@@ -61,7 +61,7 @@ from repro.energy import model as EM
 from repro.kernels.plan import build_decode_plan
 from repro.models import transformer as T
 from repro.models.moe import ParallelCtx
-from repro.obs import Telemetry
+from repro.obs import Telemetry, span
 from repro.obs import trace as TR
 from repro.serving import state as ST
 from repro.serving.pages import PagePool
@@ -107,12 +107,23 @@ class ServeStats:
     energy_j: float = 0.0  # metered inference energy (events x op energies)
     t_device_s: float = 0.0  # PCM device clock at the last decode step
     recalibrations: int = 0  # GDC recalibrations run by the drift policy
+    # host time from one step's token read-back to the next step's
+    # dispatch, counted only when the earlier step left work behind
+    host_turnaround_s: float = 0.0
+    turnarounds: int = 0
     # paged serving (zeros on the dense path)
     prefix_hits: int = 0  # prefix-cache page hits across admissions
     prefix_hit_tokens: int = 0  # prompt positions skipped via shared pages
     cow_copies: int = 0  # copy-on-write page duplications
     pages_in_use_peak: int = 0  # peak simultaneously-referenced pool pages
     peak_active_slots: int = 0  # max slots concurrently occupied
+    pool_writes: int = 0  # executions of helpers that rewrite the whole state
+    # per step, summed: occupied slots' positions (pos + 1), their page
+    # columns (ceil((pos + 1) / page_len)), and the page columns one
+    # layer's attention kernel visits (DecodePlan.page_columns)
+    live_positions: int = 0
+    page_cols_live: int = 0
+    page_cols_visited: int = 0
 
     @property
     def tokens_per_sec(self) -> float:
@@ -253,6 +264,7 @@ class BatchScheduler:
             self._phase = [DECODE] * slots
             self._slot_reserved = [0] * slots
             self._chain = [0] * slots  # prefix-cache chain id per slot
+            self._page_cols = self.plan.page_columns(slots, self.max_pages)
         else:
             self.state = self._place_state(ST.init_state(cfg, slots, cache_len))
             if placement is None:
@@ -286,6 +298,9 @@ class BatchScheduler:
         self.request_spikes: Dict[int, float] = {}
         self.stats = self._fresh_stats()
         self._next_rid = 0
+        # token read-back end of the last step that left work behind (the
+        # start of the next counted turnaround), else None
+        self._turn_from: Optional[float] = None
         # PCM device clock (drift lifecycle): picks up wherever the
         # programmed params already are — the device does not rejuvenate
         self._t_device = AD.device_time(params)
@@ -333,6 +348,19 @@ class BatchScheduler:
         ("prefix_hit_tokens", "prefix_hit_tokens_total",
          "prompt positions skipped via shared pages"),
         ("cow_copies", "cow_copies_total", "copy-on-write page duplications"),
+        ("host_turnaround_s", "host_turnaround_seconds_total",
+         "host time from token read-back to the next dispatch"),
+        ("turnarounds", "host_turnarounds_total",
+         "step-to-step turnarounds counted"),
+        ("pool_writes", "pool_writes_total",
+         "executions of helpers that rewrite the whole paged state"),
+        ("live_positions", "live_positions_total",
+         "occupied slots' cached positions, summed over steps"),
+        ("page_cols_live", "page_columns_live_total",
+         "page columns holding live positions, summed over steps"),
+        ("page_cols_visited", "page_columns_visited_total",
+         "page columns one layer's attention kernel visits, summed over "
+         "steps"),
     )
 
     def attach_obs(self, obs: Telemetry) -> None:
@@ -341,7 +369,11 @@ class BatchScheduler:
         self.obs = obs
         m = obs.metrics
         self._h_step = m.histogram(
-            "decode_step_seconds", "batched decode_step latency")
+            "decode_step_seconds",
+            "host time from decode_step dispatch to its tokens on the host")
+        self._h_turnaround = m.histogram(
+            "host_turnaround_seconds",
+            "host time from token read-back to the next dispatch")
         self._g_active = m.gauge("active_slots", "slots holding a request")
         self._g_queue = m.gauge(
             "scheduler_queue_depth", "submitted-not-yet-admitted requests")
@@ -475,6 +507,7 @@ class BatchScheduler:
         self.request_spikes = {}
         self.stats = self._fresh_stats()
         self.stats.t_device_s = self._t_device
+        self._turn_from = None
         if self.obs is not None:
             # counters are lifetime-monotone; only the delta marks rebase
             # onto the fresh ServeStats / PagePool
@@ -576,6 +609,12 @@ class BatchScheduler:
 
     # -- paged-mode page plumbing --------------------------------------
 
+    def _write_pool(self, fn, *args) -> None:
+        """Run a helper that takes and returns the whole paged state (so
+        rewrites the whole pool), counted in ``ServeStats.pool_writes``."""
+        self.stats.pool_writes += 1
+        self.state = fn(self.state, *args)
+
     def _zero_freed(self, pids: List[int]) -> None:
         """Zero freed physical pages on device (fixed-size jitted batches,
         padded with the trash page so the step compiles once)."""
@@ -584,7 +623,7 @@ class BatchScheduler:
             batch = np.full((chunk,), ST.TRASH_PAGE, np.int32)
             part = pids[i:i + chunk]
             batch[:len(part)] = part
-            self.state = self._zero_pages_fn(self.state, jnp.asarray(batch))
+            self._write_pool(self._zero_pages_fn, jnp.asarray(batch))
 
     def _cow(self, slot: int, tp: int, src: int, keep_upto: int) -> int:
         """Copy-on-write: give ``slot`` exclusive ownership of logical block
@@ -592,10 +631,10 @@ class BatchScheduler:
         in-page positions) into a fresh page and repointing its table."""
         dst = self.pages.alloc(reserved=True)
         self._slot_reserved[slot] -= 1
-        self.state = self._copy_page(self.state, jnp.int32(src),
-                                     jnp.int32(dst), jnp.int32(keep_upto))
-        self.state = self._set_entry(self.state, jnp.int32(slot),
-                                     jnp.int32(tp), jnp.int32(dst))
+        self._write_pool(self._copy_page, jnp.int32(src), jnp.int32(dst),
+                         jnp.int32(keep_upto))
+        self._write_pool(self._set_entry, jnp.int32(slot), jnp.int32(tp),
+                         jnp.int32(dst))
         self._table_rows[slot, tp] = dst
         if self.pages.release(src):  # cache entry may have been LRU-evicted
             self._zero_freed([src])
@@ -689,9 +728,9 @@ class BatchScheduler:
                 row[n_ctx // pl_] = partial_pid
                 cursor = n_ctx
             self._table_rows[slot] = row
-            self.state = self._admit_slot(
-                self.state, jnp.int32(slot), jnp.asarray(row),
-                jnp.uint32(req.seed), jnp.int32(cursor))
+            self._write_pool(self._admit_slot, jnp.int32(slot),
+                             jnp.asarray(row), jnp.uint32(req.seed),
+                             jnp.int32(cursor))
             self._slot_req[slot] = req
             self._remaining[slot] = req.max_new
             self._slot_pos[slot] = cursor
@@ -794,7 +833,7 @@ class BatchScheduler:
             self._cursor[slot] = 0
             self._phase[slot] = DECODE
             self._chain[slot] = 0
-            self.state = self._release_slot(self.state, jnp.int32(slot))
+            self._write_pool(self._release_slot, jnp.int32(slot))
         else:
             self.state = self._release(self.state, slot)
         self.stats.evictions += 1
@@ -823,42 +862,39 @@ class BatchScheduler:
         the slot's request — and (b) advances the PCM drift lifecycle when
         a :class:`~repro.aimc_device.DriftPolicy` is set on programmed
         params (device clock from decode wall time, periodic GDC
-        recalibration), without recompiling the jitted decode."""
-        if self.paged:
-            return self._step_paged()
-        self.admit()
+        recalibration), without recompiling the jitted decode.  Its phases
+        are the ``xpk.sched.*`` spans (:mod:`repro.obs.spans`)."""
+        with span("sched.step"):
+            return self._step_paged() if self.paged else self._step_dense()
+
+    def _step_dense(self) -> int:
+        with span("sched.admit"):
+            self.admit()
         if not any(r is not None for r in self._slot_req):
             return 0
-        t0 = time.perf_counter()  # monotonic: durations must survive NTP
-        logits, self.state, act = self._decode(*self._decode_args())
-        nxt = np.asarray(self.state.tokens)  # syncs the step
-        step_s = time.perf_counter() - t0
-        self.stats.decode_s += step_s
-        self.stats.decode_steps += 1
-        act = np.asarray(act)
-        obs = self.obs
-        decoded = 0
-        for slot in range(self.slots):
-            req = self._slot_req[slot]
-            if req is None:
-                continue
-            out = self.outputs[req.rid]
-            out.append(int(nxt[slot]))
-            decoded += 1
-            if obs is not None:
-                obs.trace(TR.FIRST_TOKEN if len(out) == 1 else TR.DECODE,
-                          rid=req.rid, slot=slot, token=int(nxt[slot]),
-                          pos=len(out))
-            self._book_position(req.rid, float(act[slot]))
-            self._remaining[slot] -= 1
-            if self._remaining[slot] == 0:
+        nxt, act, step_s = self._dispatch()
+        with span("sched.commit"):
+            obs = self.obs
+            decoded = 0
+            for slot in range(self.slots):
+                req = self._slot_req[slot]
+                if req is None:
+                    continue
+                out = self.outputs[req.rid]
+                out.append(int(nxt[slot]))
+                decoded += 1
                 if obs is not None:
-                    obs.trace(TR.FINISH, rid=req.rid, slot=slot,
-                              tokens=len(out))
-                self.evict(slot)
-        self.stats.decoded_tokens += decoded
-        self._advance_device_clock(step_s)
-        self._obs_step(step_s, decoded)
+                    obs.trace(TR.FIRST_TOKEN if len(out) == 1 else TR.DECODE,
+                              rid=req.rid, slot=slot, token=int(nxt[slot]),
+                              pos=len(out))
+                self._book_position(req.rid, float(act[slot]))
+                self._remaining[slot] -= 1
+                if self._remaining[slot] == 0:
+                    if obs is not None:
+                        obs.trace(TR.FINISH, rid=req.rid, slot=slot,
+                                  tokens=len(out))
+                    self.evict(slot)
+            self._end_step(step_s, decoded)
         return decoded
 
     def _step_paged(self) -> int:
@@ -873,91 +909,128 @@ class BatchScheduler:
         boundaries, copy-on-write off shared pages); idle slots write the
         trash page.  One jitted function serves all of it, compiled once.
         Returns #tokens decoded (prompt chunks don't count)."""
-        self.admit()
+        with span("sched.admit"):
+            self.admit()
         if not any(r is not None for r in self._slot_req):
             return 0
         b = self.slots
-        feed = self._blank_feed()
-        feed_tok, feed_seed, feed_mask, write_pids = feed
-        for slot in range(b):
-            req = self._slot_req[slot]
-            if req is None:
-                feed_mask[slot] = True  # pin idle slots to token 0 / stream 0
-                continue
-            p = self._slot_pos[slot]
-            tp, off = divmod(p, self.page_len)
-            pid = int(self._table_rows[slot, tp])
-            if pid == ST.NULL_PAGE:  # block boundary: open a fresh page
-                pid = self.pages.alloc(reserved=True)
-                self._slot_reserved[slot] -= 1
-                self._table_rows[slot, tp] = pid
-                self.state = self._set_entry(self.state, jnp.int32(slot),
-                                             jnp.int32(tp), jnp.int32(pid))
-            elif self.pages.refcount[pid] > 1:  # shared (prefix cache): CoW
-                pid = self._cow(slot, tp, pid, off)
-            write_pids[slot] = pid
-            phase = self._phase[slot]
-            if phase == PREFILL:
-                cur = self._cursor[slot]
-                feed_tok[slot] = req.prompt_np[cur]
-                feed_seed[slot] = req.ckeys[cur]
-                feed_mask[slot] = True
-            elif phase == HANDOFF:
-                feed_tok[slot] = req.prompt_np[-1]
-                feed_seed[slot] = req.seed
-                feed_mask[slot] = True
-        t0 = time.perf_counter()  # monotonic: durations must survive NTP
-        logits, self.state, act = self._decode(*self._decode_args(feed))
-        nxt = np.asarray(self.state.tokens)  # syncs the step
-        step_s = time.perf_counter() - t0
-        self.stats.decode_s += step_s
-        self.stats.decode_steps += 1
-        act = np.asarray(act)
-        obs = self.obs
-        decoded = 0
-        for slot in range(b):
-            req = self._slot_req[slot]
-            if req is None:
-                continue
-            self._slot_pos[slot] += 1
-            phase = self._phase[slot]
-            if phase == PREFILL:
-                self._cursor[slot] += 1
-                cur = self._cursor[slot]
-                self.stats.prefill_tokens += 1
-                if obs is not None:
-                    obs.trace(TR.PREFILL_CHUNK, rid=req.rid, slot=slot,
-                              pos=cur, n_ctx=req.n_ctx)
-                if cur % self.page_len == 0:  # completed block: publish it
-                    self._register_prefix(slot, cur)
-                if cur == req.n_ctx:
-                    if req.n_ctx % self.page_len:
-                        self._register_prefix(slot, req.n_ctx)
-                    self._phase[slot] = HANDOFF
-            else:
-                out = self.outputs[req.rid]
-                out.append(int(nxt[slot]))
-                decoded += 1
-                if obs is not None:
-                    obs.trace(TR.FIRST_TOKEN if len(out) == 1 else TR.DECODE,
-                              rid=req.rid, slot=slot, token=int(nxt[slot]),
-                              pos=len(out))
-                if phase == HANDOFF:
-                    self._phase[slot] = DECODE
-                self._remaining[slot] -= 1
-            self._book_position(req.rid, float(act[slot]))
-            if self._remaining[slot] == 0:
-                if obs is not None:
-                    obs.trace(TR.FINISH, rid=req.rid, slot=slot,
-                              tokens=len(self.outputs[req.rid]))
-                self.evict(slot)
+        st = self.stats
+        with span("sched.prepare"):
+            feed = self._blank_feed()
+            feed_tok, feed_seed, feed_mask, write_pids = feed
+            live = cols = 0
+            for slot in range(b):
+                req = self._slot_req[slot]
+                if req is None:
+                    feed_mask[slot] = True  # pin idle slots to token 0 / stream 0
+                    continue
+                p = self._slot_pos[slot]
+                tp, off = divmod(p, self.page_len)
+                live += p + 1
+                cols += tp + 1
+                pid = int(self._table_rows[slot, tp])
+                if pid == ST.NULL_PAGE:  # block boundary: open a fresh page
+                    pid = self.pages.alloc(reserved=True)
+                    self._slot_reserved[slot] -= 1
+                    self._table_rows[slot, tp] = pid
+                    self._write_pool(self._set_entry, jnp.int32(slot),
+                                     jnp.int32(tp), jnp.int32(pid))
+                elif self.pages.refcount[pid] > 1:  # shared (prefix cache): CoW
+                    pid = self._cow(slot, tp, pid, off)
+                write_pids[slot] = pid
+                phase = self._phase[slot]
+                if phase == PREFILL:
+                    cur = self._cursor[slot]
+                    feed_tok[slot] = req.prompt_np[cur]
+                    feed_seed[slot] = req.ckeys[cur]
+                    feed_mask[slot] = True
+                elif phase == HANDOFF:
+                    feed_tok[slot] = req.prompt_np[-1]
+                    feed_seed[slot] = req.seed
+                    feed_mask[slot] = True
+            st.live_positions += live
+            st.page_cols_live += cols
+            st.page_cols_visited += self._page_cols
+        nxt, act, step_s = self._dispatch(feed)
+        with span("sched.commit"):
+            obs = self.obs
+            decoded = 0
+            for slot in range(b):
+                req = self._slot_req[slot]
+                if req is None:
+                    continue
+                self._slot_pos[slot] += 1
+                phase = self._phase[slot]
+                if phase == PREFILL:
+                    self._cursor[slot] += 1
+                    cur = self._cursor[slot]
+                    st.prefill_tokens += 1
+                    if obs is not None:
+                        obs.trace(TR.PREFILL_CHUNK, rid=req.rid, slot=slot,
+                                  pos=cur, n_ctx=req.n_ctx)
+                    if cur % self.page_len == 0:  # completed block: publish it
+                        self._register_prefix(slot, cur)
+                    if cur == req.n_ctx:
+                        if req.n_ctx % self.page_len:
+                            self._register_prefix(slot, req.n_ctx)
+                        self._phase[slot] = HANDOFF
+                else:
+                    out = self.outputs[req.rid]
+                    out.append(int(nxt[slot]))
+                    decoded += 1
+                    if obs is not None:
+                        obs.trace(
+                            TR.FIRST_TOKEN if len(out) == 1 else TR.DECODE,
+                            rid=req.rid, slot=slot, token=int(nxt[slot]),
+                            pos=len(out))
+                    if phase == HANDOFF:
+                        self._phase[slot] = DECODE
+                    self._remaining[slot] -= 1
+                self._book_position(req.rid, float(act[slot]))
+                if self._remaining[slot] == 0:
+                    if obs is not None:
+                        obs.trace(TR.FINISH, rid=req.rid, slot=slot,
+                                  tokens=len(self.outputs[req.rid]))
+                    self.evict(slot)
+            st.pages_in_use_peak = max(st.pages_in_use_peak,
+                                       self.pages.peak_in_use)
+            st.cow_copies = self.pages.cow_copies
+            self._end_step(step_s, decoded)
+        return decoded
+
+    def _dispatch(self, feed=None):
+        """Run the jitted step and read its tokens and spike activity back
+        (the ``sched.dispatch`` and ``sched.readback`` spans).  Books the
+        host turnaround since the last step that left work, and the step's
+        host time from dispatch to tokens on the host."""
+        st = self.stats
+        with span("sched.dispatch"):
+            t0 = time.perf_counter()  # monotonic: durations must survive NTP
+            if self._turn_from is not None:
+                turn = t0 - self._turn_from
+                st.host_turnaround_s += turn
+                st.turnarounds += 1
+                if self.obs is not None:
+                    self._h_turnaround.observe(turn)
+            logits, self.state, act = self._decode(*self._decode_args(feed))
+        with span("sched.readback"):
+            nxt = np.asarray(self.state.tokens)  # syncs the step
+            step_s = time.perf_counter() - t0
+            act = np.asarray(act)
+        self._turn_from = time.perf_counter()  # see _end_step
+        st.decode_s += step_s
+        st.decode_steps += 1
+        return nxt, act, step_s
+
+    def _end_step(self, step_s: float, decoded: int) -> None:
+        """After a step's slots are booked: tokens, drift clock, telemetry,
+        and whether the next dispatch ends a counted turnaround (only when
+        this step left a slot occupied or a request queued)."""
         self.stats.decoded_tokens += decoded
-        self.stats.pages_in_use_peak = max(self.stats.pages_in_use_peak,
-                                           self.pages.peak_in_use)
-        self.stats.cow_copies = self.pages.cow_copies
         self._advance_device_clock(step_s)
         self._obs_step(step_s, decoded)
-        return decoded
+        if not (self._queue or any(r is not None for r in self._slot_req)):
+            self._turn_from = None
 
     def _advance_device_clock(self, step_wall_s: float) -> None:
         """Drift lifecycle: age the programmed PCM state and periodically

@@ -16,8 +16,11 @@ The scheduler-facing tests run on the CI backend matrix
 Python and run once.
 """
 
+import asyncio
+import gc
 import json
 import re
+from pathlib import Path
 
 import jax
 import pytest
@@ -38,6 +41,7 @@ from repro.obs import (
     perfetto_export,
     render_prometheus,
 )
+from repro.obs import spans as SP
 from repro.obs import trace as TR
 from repro.server import FrontDoor
 from repro.serving import BatchScheduler
@@ -297,7 +301,9 @@ def paged_sched(spiking_setup, engine_backend):
 
 
 def test_telemetry_bitexact_and_compile_once(paged_sched, tmp_path):
-    """Attaching the full bundle changes no token, no joule, no compile."""
+    """Attaching the full bundle changes no token, no joule, no compile —
+    with the GC hook installed, collections forced mid-serve and the
+    ``xpk.*`` spans recording into a live profiler session."""
     sch = paged_sched
     jobs = _jobs()
     want = _run(sch, jobs)  # telemetry-off baseline (also jit warmup)
@@ -306,14 +312,21 @@ def test_telemetry_bitexact_and_compile_once(paged_sched, tmp_path):
 
     sink = ListSink()
     obs = Telemetry.create(flight_dir=str(tmp_path))
+    assert SP._GC_SPANS in gc.callbacks
     obs.tracer.add_sink(sink)
+    # a collection inside every step's commit phase
+    obs.tracer.add_sink(lambda ev: ev["event"] == TR.FIRST_TOKEN
+                        and gc.collect())
     sch.attach_obs(obs)
-    got = _run(sch, jobs)
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        got = _run(sch, jobs)
     assert got == want  # bit-exact token streams
     assert sch.stats.energy_j == base_energy  # bit-exact energy
     assert sch.stats.spike_events == base_spikes
     assert sch._decode._cache_size() == 1, \
         "attaching telemetry recompiled the decode step"
+    gc_s = obs.metrics.get("xpike_gc_pause_seconds_total")
+    assert gc_s.value("2") > 0.0
 
     # lifecycle trace covers the whole request arc
     names = {e["event"] for e in sink.events}
@@ -391,6 +404,187 @@ def test_frontdoor_stats_nests_registry(paged_sched):
     assert any(k.startswith("admit") for k in admits)
 
 
+# -- program spans and counters --------------------------------------------
+
+
+def test_gc_hook_spans_and_counts():
+    """One callback per process however many bundles exist; every live
+    registry counts each collection's pause by generation."""
+    a, b = Telemetry.create(), Telemetry.create()
+    assert gc.callbacks.count(SP._GC_SPANS) == 1
+    ca = a.metrics.get("xpike_gc_pause_seconds_total")
+    cb = b.metrics.get("xpike_gc_pause_seconds_total")
+    before = ca.value("2"), cb.value("2")
+    gc.collect()
+    assert ca.value("2") > before[0] and cb.value("2") > before[1]
+    assert ca.label_names == ("generation",)
+
+
+def _xpk_spans(logdir):
+    """(thread, name, start_ns, end_ns, stats) of every ``xpk.*`` host
+    event in the newest profile under ``logdir``."""
+    from jax.profiler import ProfileData
+
+    path = sorted(Path(logdir).glob("**/*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(SP.SPAN_PREFIX):
+                    out.append((line.name, e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+_PARENT = {
+    "xpk.pump.intake": "xpk.pump", "xpk.pump.admit": "xpk.pump",
+    "xpk.pump.stream": "xpk.pump", "xpk.sched.step": "xpk.pump",
+    "xpk.sched.admit": "xpk.sched.step",
+    "xpk.sched.prepare": "xpk.sched.step",
+    "xpk.sched.dispatch": "xpk.sched.step",
+    "xpk.sched.readback": "xpk.sched.step",
+    "xpk.sched.commit": "xpk.sched.step",
+}
+
+
+def test_program_spans_nest_in_profiler_trace(paged_sched, tmp_path):
+    """Every ``xpk.*`` span of a front-door round over the paged scheduler
+    lands in a CPU profiler trace, nested as documented, one per round or
+    step; a collection shows as ``xpk.gc`` with its generation."""
+    sch = paged_sched
+    jobs = _jobs()
+    sch.reset()
+
+    async def go():
+        front = FrontDoor(sch)
+        await front.start()
+        try:
+            streams = [await front.submit(p, mn, seed=s)
+                       for p, mn, s in jobs]
+            return [await ts.tokens() for ts in streams]
+        finally:
+            await front.stop()
+
+    with jax.profiler.trace(str(tmp_path)):
+        asyncio.run(go())
+        gc.collect()
+    spans = _xpk_spans(tmp_path)
+    names = {name for _, name, *_ in spans}
+    assert set(_PARENT) | {"xpk.pump", "xpk.gc"} <= names
+    for thread, name, s, e, _ in spans:
+        parent = _PARENT.get(name)
+        if parent is None:
+            continue
+        assert any(t == thread and n == parent and ps <= s and e <= pe
+                   for t, n, ps, pe, _ in spans), f"{name} outside {parent}"
+    steps = sum(n == "xpk.sched.step" for _, n, *_ in spans)
+    assert sum(n == "xpk.sched.dispatch" for _, n, *_ in spans) == \
+        sch.stats.decode_steps <= steps
+    assert any(n == "xpk.gc" and st.get("generation") == 2
+               for _, n, _, _, st in spans)
+
+
+def test_pool_writes_and_live_columns_counted(paged_sched, monkeypatch):
+    """``pool_writes`` counts every call of the whole-state helpers, and
+    the live positions / page columns match the positions each request
+    held, recomputed from its prompt, its output and its prefix hit."""
+    sch = paged_sched
+    calls = []
+    for name in ("_set_entry", "_copy_page", "_zero_pages_fn",
+                 "_admit_slot", "_release_slot"):
+        fn = getattr(sch, name)
+        monkeypatch.setattr(sch, name, lambda *a, _f=fn, _n=name:
+                            calls.append(_n) or _f(*a))
+    sch.reset()
+    obs = Telemetry.create()
+    sink = ListSink()
+    obs.tracer.add_sink(sink)
+    sch.attach_obs(obs)
+    base = list(range(3, 16))  # 12 context positions: a page and a half
+    # the third request reuses the first's pages: a full-page hit and a
+    # copy-on-write of the shared partial page
+    jobs = [(base, 4, 1), (list(range(20, 27)), 8, 2), (base, 5, 3)]
+    rids = [sch.submit(p, mn, seed=s) for p, mn, s in jobs]
+    sch.run()
+    st, pl_ = sch.stats, sch.page_len
+    assert st.cow_copies >= 1 and st.prefix_hit_tokens > 0
+    assert set(calls) == {"_set_entry", "_copy_page", "_zero_pages_fn",
+                          "_admit_slot", "_release_slot"}
+    assert st.pool_writes == len(calls)
+    hit = {ev["rid"]: ev["prefix_hit_tokens"] for ev in sink.events
+           if ev["event"] == TR.ADMIT}
+    live = cols = 0
+    for rid, (prompt, _, _) in zip(rids, jobs):
+        held = range(hit[rid], len(prompt) - 1 + len(sch.outputs[rid]))
+        live += sum(p + 1 for p in held)
+        cols += sum(p // pl_ + 1 for p in held)
+    assert (st.live_positions, st.page_cols_live) == (live, cols)
+    assert st.page_cols_visited == st.decode_steps * sch.slots * sch.max_pages
+    assert obs.metrics.get("xpike_pool_writes_total").value() == len(calls)
+
+
+def _paged_grids(jaxpr):
+    """Grids of the pallas_calls that gather pages (one scalar-prefetch
+    operand: the page table) anywhere in ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            gm = eqn.params["grid_mapping"]
+            if gm.num_index_operands == 1:
+                yield gm.grid
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(x, "jaxpr", x)
+                if hasattr(sub, "eqns"):
+                    yield from _paged_grids(sub)
+
+
+@pytest.mark.parametrize("kernel", ["fused", "unfused"])
+def test_page_columns_match_kernel_grid(spiking_setup, kernel):
+    """``DecodePlan.page_columns`` is the (slot, page column) extent of the
+    paged attention kernel's grid in the traced decode step."""
+    cfg, params = spiking_setup
+    sch = BatchScheduler(params, cfg, get_backend("pallas"), slots=3,
+                         cache_len=32, paged=True, page_len=8, n_pages=16,
+                         decode_kernel=kernel)
+    grids = list(_paged_grids(
+        jax.make_jaxpr(sch._decode)(*sch._decode_args()).jaxpr))
+    assert grids, "no paged attention kernel in the decode step"
+    for grid in grids:
+        assert (grid[0], grid[-1]) == (3, 4)
+        assert grid[0] * grid[-1] == sch.plan.page_columns(3, 4) \
+            == sch._page_cols
+
+
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+def test_turnarounds_count_only_busy_gaps(paged_sched, spiking_setup, layout):
+    """A turnaround runs from one step's read-back to the next dispatch,
+    and only while the earlier step left work: a run that stays busy
+    counts ``decode_steps - 1``, an idle wait between runs counts none."""
+    if layout == "paged":
+        sch = paged_sched
+    else:
+        cfg, params = spiking_setup
+        sch = BatchScheduler(params, cfg, get_backend("reference"), slots=2,
+                             cache_len=32)
+    obs = Telemetry.create()
+    sch.attach_obs(obs)
+    jobs = _jobs()
+    _run(sch, jobs)
+    st = sch.stats
+    assert st.decode_steps > 1
+    assert st.turnarounds == st.decode_steps - 1
+    assert st.host_turnaround_s > 0.0
+    hist = obs.metrics.get("xpike_host_turnaround_seconds")
+    assert hist.snapshot()["count"] == st.turnarounds
+    assert hist.snapshot()["sum"] == pytest.approx(st.host_turnaround_s)
+    for p, mn, s in jobs:  # a second busy run after the drain, no reset
+        sch.submit(p, mn, seed=s)
+    sch.run()
+    assert sch.stats.turnarounds == sch.stats.decode_steps - 2
+
+
 def test_flight_recorder_dumps_on_pool_guard(spiking_setup, tmp_path):
     """A PagePool double-free still raises, and the armed recorder writes
     a postmortem first (events + metrics snapshot + reason)."""
@@ -409,6 +603,7 @@ def test_flight_recorder_dumps_on_pool_guard(spiking_setup, tmp_path):
     assert len(obs.recorder.dumps) == 1
     dump = json.load(open(obs.recorder.dumps[0]))
     assert "double free" in dump["reason"]
+    assert dump["events"][-1]["event"] == TR.GUARD
     assert any(e["event"] == TR.SUBMIT for e in dump["events"])
     assert "xpike_decode_steps_total" in dump["metrics"]
 
