@@ -117,7 +117,9 @@ class ServeStats:
     cow_copies: int = 0  # copy-on-write page duplications
     pages_in_use_peak: int = 0  # peak simultaneously-referenced pool pages
     peak_active_slots: int = 0  # max slots concurrently occupied
-    pool_writes: int = 0  # executions of helpers that rewrite the whole state
+    # executions of the page helpers, each editing the donated paged state
+    # in place (a page-table entry, a slot's row, or a batch of pages)
+    pool_writes: int = 0
     # per step, summed: occupied slots' positions (pos + 1), their page
     # columns (ceil((pos + 1) / page_len)), and the page columns one
     # layer's attention kernel visits (DecodePlan.page_columns)
@@ -241,16 +243,12 @@ class BatchScheduler:
                     slots, cache_len, self.n_pages, page_len)
                 state_sh = placement.paged_state_shardings(
                     slots, cache_len, self.n_pages, page_len)
-                self._admit_slot = jax.jit(ST.paged_admit_slot,
-                                           out_shardings=state_sh)
-                self._release_slot = jax.jit(ST.paged_release_slot,
-                                             out_shardings=state_sh)
-                self._set_entry = jax.jit(ST.paged_set_table_entry,
-                                          out_shardings=state_sh)
-                self._zero_pages_fn = jax.jit(ST.pool_zero_pages,
-                                              out_shardings=state_sh)
-                self._copy_page = jax.jit(ST.pool_copy_page,
-                                          out_shardings=state_sh)
+                (self._admit_slot, self._release_slot, self._set_entry,
+                 self._zero_pages_fn, self._copy_page) = (
+                    ST.jit_paged_helper(fn, out_shardings=state_sh)
+                    for fn in (ST.paged_admit_slot, ST.paged_release_slot,
+                               ST.paged_set_table_entry, ST.pool_zero_pages,
+                               ST.pool_copy_page))
             self._decode = ST.make_paged_decode_fn(
                 cfg, self.pctx, backend, out_shardings=decode_out,
                 plan=self.plan)
@@ -353,7 +351,7 @@ class BatchScheduler:
         ("turnarounds", "host_turnarounds_total",
          "step-to-step turnarounds counted"),
         ("pool_writes", "pool_writes_total",
-         "executions of helpers that rewrite the whole paged state"),
+         "executions of the page helpers that edit the paged state"),
         ("live_positions", "live_positions_total",
          "occupied slots' cached positions, summed over steps"),
         ("page_cols_live", "page_columns_live_total",
@@ -610,8 +608,10 @@ class BatchScheduler:
     # -- paged-mode page plumbing --------------------------------------
 
     def _write_pool(self, fn, *args) -> None:
-        """Run a helper that takes and returns the whole paged state (so
-        rewrites the whole pool), counted in ``ServeStats.pool_writes``."""
+        """Run a page helper on the paged state, counted in
+        ``ServeStats.pool_writes``.  The helper takes the state donated and
+        edits it in place, so the old ``self.state`` is deleted by the call
+        and replaced with the result at once."""
         self.stats.pool_writes += 1
         self.state = fn(self.state, *args)
 

@@ -293,8 +293,14 @@ def paged_set_table_entry(state: PagedDecodeState, slot, idx, pid
 def pool_zero_pages(state: PagedDecodeState, pids: Array) -> PagedDecodeState:
     """Zero a fixed-size batch of physical pages (freed pages must read as
     zero spikes before reuse; pad the batch with TRASH_PAGE ids)."""
-    def z(leaf):
-        return leaf.at[pids].set(jnp.zeros((), leaf.dtype))
+    def z(leaf):  # [n_pages, T, KV, page_len, hd]
+        # one masked pass that edits the donated pool in place: the TPU
+        # lays the page axis out minor-most, so a batched scatter would
+        # re-lay the whole pool out and back, and page-by-page updates
+        # are strided writes
+        keep = jnp.ones((leaf.shape[0],), bool).at[pids].set(False)
+        return jnp.where(keep[:, None, None, None, None], leaf,
+                         jnp.zeros((), leaf.dtype))
 
     return dataclasses.replace(state, pool=_map_pool(state.pool, z))
 
@@ -312,11 +318,20 @@ def pool_copy_page(state: PagedDecodeState, src, dst, keep_upto
     return dataclasses.replace(state, pool=_map_pool(state.pool, cp))
 
 
-paged_admit_slot_jit = jax.jit(paged_admit_slot)
-paged_release_slot_jit = jax.jit(paged_release_slot)
-paged_set_table_entry_jit = jax.jit(paged_set_table_entry)
-pool_zero_pages_jit = jax.jit(pool_zero_pages)
-pool_copy_page_jit = jax.jit(pool_copy_page)
+def jit_paged_helper(fn, **jit_kwargs):
+    """Jit a paged-state helper with the state (argument 0) donated: XLA
+    aliases it into the result, so a page-table edit or a page copy updates
+    the pool in place instead of writing a fresh copy of it.  The call
+    deletes the caller's state — replace it with the result and keep no
+    other reference to it."""
+    return jax.jit(fn, donate_argnums=0, **jit_kwargs)
+
+
+paged_admit_slot_jit = jit_paged_helper(paged_admit_slot)
+paged_release_slot_jit = jit_paged_helper(paged_release_slot)
+paged_set_table_entry_jit = jit_paged_helper(paged_set_table_entry)
+pool_zero_pages_jit = jit_paged_helper(pool_zero_pages)
+pool_copy_page_jit = jit_paged_helper(pool_copy_page)
 
 
 # ---------------------------------------------------------------------------

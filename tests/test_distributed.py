@@ -160,6 +160,25 @@ def test_sharded_paged_scheduler_bit_exact(spiking_setup, mesh, backend_cls):
     assert [o1[r1]] == ref1 and [o2[r2]] == ref2
     assert sch.stats.prefix_hit_tokens == 16, "prefix cache must hit on mesh"
 
+    # a partial-tail hit: the second serve copy-on-writes the shared tail
+    # page through the placed helpers, which take the sharded state
+    # donated — a state kept across a helper call is deleted, not stale
+    tail = _prompt(9, 14)  # n_ctx=13: one full 8-block + a 5-position tail
+    ref3, _ = _oracle_run(cfg, params, [tail], 4, seed0=3)
+    ref4, _ = _oracle_run(cfg, params, [tail], 4, seed0=4)
+    sch = ex.scheduler(slots=2, cache_len=32, paged=True, page_len=8)
+    r3 = sch.submit(tail, 4, seed=3)
+    o3 = dict(sch.run())
+    stale = sch.state
+    r4 = sch.submit(tail, 4, seed=4)
+    o4 = dict(sch.run())
+    assert [o3[r3]] == ref3 and [o4[r4]] == ref4
+    assert sch.stats.cow_copies >= 1, "the tail hit must copy-on-write"
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(stale.pool))
+    for fn in (sch._admit_slot, sch._release_slot, sch._set_entry,
+               sch._zero_pages_fn, sch._copy_page):
+        assert fn._cache_size() == 1, "a placed page helper recompiled"
+
 
 @needs_mesh
 @pytest.mark.parametrize("backend_cls", [IntegerBackend, PallasBackend])

@@ -10,7 +10,10 @@ paged-KV-cache PR):
   and unrolled ``remainder`` leaves);
 * the page economics guard rails: double-free, use-after-free retain,
   evicting an unoccupied slot, and foreign-page release all raise;
-* pool page copy (copy-on-write) keeps exactly the valid prefix.
+* pool page copy (copy-on-write) keeps exactly the valid prefix;
+* the jitted page helpers take the paged state donated: they edit it in
+  place (the result lives in the input's buffers, the input is deleted)
+  and compute exactly what the undonated helper computes.
 """
 
 import dataclasses
@@ -26,8 +29,10 @@ from repro.models import transformer as T
 from repro.serving import (NULL_PAGE, RESERVED_PAGES, TRASH_PAGE,
                            BatchScheduler, PagePool, init_paged_state,
                            init_state, paged_admit_slot, paged_release_slot,
-                           pool_copy_page, pool_zero_pages, release_slot,
-                           slot_slice, splice_request)
+                           paged_set_table_entry, pool_copy_page,
+                           pool_zero_pages, release_slot, slot_slice,
+                           splice_request)
+from repro.serving import state as ST
 
 SPIKING = "xpikeformer-gpt-4-256"
 ANN = "yi-9b"
@@ -167,6 +172,51 @@ def test_pool_zero_pages(spiking_cfg):
     for leaf in jax.tree.leaves(st.pool):
         arr = _pages_first(leaf)
         assert arr[[2, 5]].sum() == 0 and (arr[[3, 4]] == 1).all()
+
+
+def _row(*pids):
+    return jnp.asarray(list(pids) + [NULL_PAGE] * (4 - len(pids)), jnp.int32)
+
+
+# (plain helper, its donating jit, the arguments after the state)
+DONATED_HELPERS = {
+    "admit_slot": (paged_admit_slot, ST.paged_admit_slot_jit,
+                   (jnp.int32(1), _row(6, 7, 8), jnp.uint32(9),
+                    jnp.int32(16))),
+    "release_slot": (paged_release_slot, ST.paged_release_slot_jit,
+                     (jnp.int32(0),)),
+    "set_table_entry": (paged_set_table_entry, ST.paged_set_table_entry_jit,
+                        (jnp.int32(0), jnp.int32(2), jnp.int32(5))),
+    "zero_pages": (pool_zero_pages, ST.pool_zero_pages_jit,
+                   (jnp.asarray([3, 4, TRASH_PAGE, TRASH_PAGE], jnp.int32),)),
+    "copy_page": (pool_copy_page, ST.pool_copy_page_jit,
+                  (jnp.int32(3), jnp.int32(9), jnp.int32(5))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DONATED_HELPERS))
+def test_page_helper_donates_state(remainder_cfg, name):
+    """Each jitted page helper consumes its state argument: the result's
+    pool leaves live in the input's buffers (an in-place edit, not a copy
+    of the pool), every input leaf is deleted, and the result equals the
+    undonated helper's on a copy — through both cache stackings."""
+    plain, donating, args = DONATED_HELPERS[name]
+    st = init_paged_state(remainder_cfg, 2, 32, 8, 10)
+    pool = jax.tree.map(
+        lambda a: jax.random.bernoulli(jax.random.PRNGKey(a.ndim), 0.5,
+                                       a.shape).astype(a.dtype), st.pool)
+    st = paged_admit_slot(dataclasses.replace(st, pool=pool), 0,
+                          _row(3, 4), jnp.uint32(2), jnp.int32(11))
+    want = plain(jax.tree.map(jnp.copy, st), *args)
+    ptrs = [leaf.unsafe_buffer_pointer() for leaf in jax.tree.leaves(st.pool)]
+    got = donating(st, *args)
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(st)), \
+        "the helper's state argument was not donated"
+    assert [leaf.unsafe_buffer_pointer()
+            for leaf in jax.tree.leaves(got.pool)] == ptrs, \
+        "the pool was copied instead of edited in place"
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------

@@ -311,6 +311,55 @@ def test_paged_drift_policy_soak(spiking_setup, engine_backend):
 
 
 # ---------------------------------------------------------------------------
+# Donated page helpers through the whole scheduler
+# ---------------------------------------------------------------------------
+
+
+def test_donated_page_helpers_serve_exactly_across_reset(spiking_setup):
+    """Every page helper takes the paged state donated.  A serve that
+    crosses page boundaries, hits a shared partial tail (copy-on-write),
+    finishes requests (table release, page zeroing) and is ``reset()``
+    mid-life still decodes the dense scheduler's exact tokens, each helper
+    and the decode step compiling once, and a state kept across a helper
+    call is deleted rather than silently stale."""
+    cfg, params = spiking_setup
+    shared = _prompt(5, 14)  # n_ctx=13: one full 8-block + a 5-position tail
+    mixes = [([shared, _prompt(1, 12), _prompt(2, 4)], 10),
+             ([shared, _prompt(3, 9)], 20),  # full-block + tail hits: CoW
+             ([shared, _prompt(1, 12), _prompt(2, 4)], 30)]  # after reset()
+    dense = BatchScheduler(params, cfg, IntegerBackend(), slots=2, cache_len=32)
+    paged = BatchScheduler(params, cfg, IntegerBackend(), slots=2,
+                           cache_len=32, paged=True, page_len=8)
+    # the helpers are module-level jits shared by every scheduler of the
+    # process: count only the compiles this scheduler's shapes add
+    helpers = (paged._admit_slot, paged._release_slot, paged._set_entry,
+               paged._zero_pages_fn, paged._copy_page)
+    compiled = [fn._cache_size() for fn in helpers]
+    ref, got = [], []
+    for i, (prompts, seed0) in enumerate(mixes):
+        if i == 2:
+            st = paged.stats
+            assert st.prefix_hits >= 2 and st.cow_copies >= 1
+            assert st.evictions == 5
+            assert st.pool_writes > st.admissions + st.evictions
+            dense.reset()
+            paged.reset()
+        ref.append(_run(dense, prompts, 6, seed0=seed0))
+        got.append(_run(paged, prompts, 6, seed0=seed0))
+    assert got == ref, "donated page helpers changed the served tokens"
+    assert paged._decode._cache_size() == 1, "paged decode_step recompiled"
+    for fn, before in zip(helpers, compiled):
+        assert fn._cache_size() - before <= 1, "a page helper recompiled"
+
+    stale = paged.state
+    paged.submit(shared, 2, seed=40)
+    paged.step()  # admission runs _admit_slot on the state
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(stale.pool))
+    with pytest.raises(RuntimeError):
+        np.asarray(stale.page_table)
+
+
+# ---------------------------------------------------------------------------
 # Scheduler trace oracle (pure-Python reference bookkeeping)
 # ---------------------------------------------------------------------------
 

@@ -13,6 +13,11 @@ widths:
 * ``xpikeformer-vit-8-768`` classification: T=4, 64 patches, d=768,
   12 heads x 64, MLP 3072.
 
+It also compiles the scheduler's page helpers at the serving cell's whole
+pool (64 slots, a 512-position cache in 8-token pages, 4098 pages): each
+takes the paged state donated and must edit the pool where it lies, with
+no pool-sized buffer beside it — what only the chip's layouts can show.
+
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, so the call has to
 happen in the worker that runs this file.
@@ -181,3 +186,39 @@ def test_fused_decode_layer_paged_compiles(one_chip, b, heads, with_tail):
     _compile(step, one_chip, ((b, 2), U32), ((T, b, g["d"]), F32), pool, pool,
              ((b, mp), I32), ((b,), I32), ((b,), I32),
              *_layer_weights(heads, with_tail))
+
+
+# (page helper, the arguments after the state), at the serving cell's pool
+PAGE_HELPERS = {
+    "admit_slot": ("paged_admit_slot",
+                   (((), I32), ((64,), I32), ((), U32), ((), I32))),
+    "release_slot": ("paged_release_slot", (((), I32),)),
+    "set_table_entry": ("paged_set_table_entry",
+                        (((), I32), ((), I32), ((), I32))),
+    "zero_pages": ("pool_zero_pages", (((64,), I32),)),
+    "copy_page": ("pool_copy_page", (((), I32), ((), I32), ((), I32))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PAGE_HELPERS))
+def test_page_helper_edits_pool_in_place(one_chip, name):
+    """The donated page helper aliases the whole pool into its result and
+    needs no temporary of pool size: a page-table write, a page copy or a
+    batch of zeroed pages touches its own bytes, never a copy of the pool
+    (a batched scatter, for one, re-lays the pool out and back)."""
+    from repro.configs.registry import get_config
+    from repro.serving import state as ST
+
+    fn, args = PAGE_HELPERS[name]
+    cfg = get_config("xpikeformer-gpt-8-512")
+    shapes = jax.eval_shape(
+        lambda: ST.init_paged_state(cfg, 64, 512, 8, 64 * 64 + 2))
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(shapes.pool))
+    mem = ST.jit_paged_helper(getattr(ST, fn)).lower(
+        state, *_abstract(args, one_chip)).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool was not donated"
+    assert mem.temp_size_in_bytes < pool_bytes // 100, \
+        f"{mem.temp_size_in_bytes} temporary bytes beside the pool"
